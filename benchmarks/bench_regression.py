@@ -1222,6 +1222,9 @@ def rerun_convergence(committed: dict) -> dict:
 
 
 def main(argv: list[str]) -> int:
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     args = [a for a in argv[1:] if not a.startswith("--")]
     path = args[0] if args else "BENCH_sweep.json"
     kind = "sweep"

@@ -101,7 +101,8 @@ from repro.launch.dryrun import run_cell
 res = run_cell({arch!r}, {shape!r}, False, overrides={overrides!r})
 print("RESULT" + json.dumps(res["roofline"] | {{"mem_gib": res["memory"]["peak_estimate_bytes"] / 2**30}}))
 """
-    env = dict(os.environ, PYTHONPATH="src")
+    # fake 512-device CPU mesh: the child must never take an accelerator
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     proc = subprocess.run(
         [sys.executable, "-c", code], capture_output=True, text=True, timeout=1800, env=env
     )

@@ -7,6 +7,9 @@ from __future__ import annotations
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     print("name,us_per_call,derived")
     from benchmarks import kernels_bench, paper_figs, roofline_report, tracelint_bench
 

@@ -28,9 +28,11 @@ column.
 
 import argparse
 
+import jax
 import numpy as np
 
 from repro.cluster.simulator import effective_w
+from repro.compile_cache import enable_compile_cache
 from repro.core.problems import (
     LogisticRegressionProblem,
     PCAProblem,
@@ -101,6 +103,12 @@ def main() -> None:
         "(bit-exact) and time the scalar loop (slow)",
     )
     args = ap.parse_args()
+    enable_compile_cache()
+    dev = jax.devices()[0]
+    print(
+        f"device: platform={dev.platform} kind={dev.device_kind} "
+        f"count={len(jax.devices())}"
+    )
     if args.paper_scale:
         args.problem = "pca"
     engine = EngineConfig(
@@ -169,13 +177,16 @@ def main() -> None:
         )
         print(f"scalar loop (dsag+sag pair, extrapolated): {scalar_s:.1f}s")
 
-    header = f"{'method':>6} {'w':>4} {'median t->gap (s)':>18} {'final gap':>11} {'total t (s)':>12}"
+    header = (
+        f"{'method':>6} {'w':>4} {'engine':>6} {'median t->gap (s)':>18} "
+        f"{'final gap':>11} {'total t (s)':>12}"
+    )
     print(header)
     print("-" * len(header))
     for name, res in out.results.items():
         ttg = res.time_to_gap(gap)
         print(
-            f"{name:>6} {effective_w(out.methods[name], N):>4} "
+            f"{name:>6} {effective_w(out.methods[name], N):>4} {res.engine:>6} "
             f"{np.median(ttg):>18.4f} "
             f"{np.nanmean(res.suboptimality[:, -1]):>11.2e} "
             f"{res.times[:, -1].mean():>12.3f}"

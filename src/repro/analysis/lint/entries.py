@@ -22,7 +22,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro.cluster.simulator import MethodConfig, task_finish_time
 from repro.core.problems import (
@@ -33,6 +32,7 @@ from repro.core.problems import (
 )
 from repro.experiments import fused
 from repro.latency.model import make_heterogeneous_cluster, sample_fleet
+from repro.precision import x64
 
 
 @dataclasses.dataclass
@@ -119,7 +119,7 @@ def _fused_probe(
         kernel_backend=kernel_backend,
     )
     fn = functools.partial(fused._run_scan, kernels, spec)
-    with enable_x64():
+    with x64():
         jaxpr = jax.make_jaxpr(fn)(*scan_args)
     return EntryProbe(
         name="",
@@ -144,7 +144,7 @@ def _build_latency() -> EntryProbe:
     random strictly-positive draws make any FMA contraction of the final
     multiply-add visible in the last ULP.
     """
-    with enable_x64():
+    with x64():
         batches = []
         for seed in (0, 1, 2, 3):
             rng = np.random.default_rng(seed)
@@ -236,7 +236,7 @@ def _build_fused_pca_grid_pallas() -> EntryProbe:
 def _kernels_probe(problem, name: str, description: str) -> EntryProbe:
     kernels = problem.fused_kernels()
     pad_w = 16  # width_bucket(m, n) for 8 < m <= 16 at n=64
-    with enable_x64():
+    with x64():
         starts = jnp.asarray([1, 17, 33], dtype=jnp.int64)
         widths = jnp.asarray([11, 16, 13], dtype=jnp.int64)
         Vb = jnp.zeros(
@@ -275,7 +275,7 @@ def _build_lb_update() -> EntryProbe:
 
     S, N = _PROBE_SCENARIOS, _PROBE_WORKERS
     ladder = (1, 2, 4, 8, 16)
-    with enable_x64():
+    with x64():
         rng = np.random.default_rng(7)
         args = (
             jnp.asarray(np.full((S, N), 2.0)),  # p_cur

@@ -11,7 +11,6 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro.analysis import hlo
 from repro.analysis.lint.entries import EntryProbe
@@ -24,6 +23,7 @@ from repro.analysis.lint.jaxpr_utils import (
     reaches_comparison,
     stray_chain_reads,
 )
+from repro.precision import x64
 
 #: reductions with an ``axes`` param (TL003)
 _REDUCE_PRIMS = frozenset(
@@ -43,7 +43,7 @@ def check_fma_seam(entry: EntryProbe) -> list:
     if entry.latency_probe is None:
         return []
     fn, batches = entry.latency_probe
-    with enable_x64():
+    with x64():
         # wrap in a fresh function object: jax's executable cache is keyed
         # on identity, and a stale entry (e.g. traced before the seam was
         # edited out) would mask a real regression
@@ -81,7 +81,7 @@ def _hlo_copy_evidence(entry: EntryProbe) -> str:
         return ""
     fn, args = entry.hlo_fn_args
     try:
-        with enable_x64():
+        with x64():
             text = jax.jit(fn).lower(*args).compile().as_text()
         comps, hlo_entry = hlo.parse_computations(text)
         if hlo_entry is None:

@@ -1,7 +1,7 @@
 """Three-term roofline derivation from a compiled dry-run artifact.
 
-TPU v5e constants (per instruction sheet):
-  peak compute 197 TFLOP/s bf16 / chip, HBM 819 GB/s, ICI ~50 GB/s/link.
+Per-chip peaks come from :data:`PEAKS`, keyed by ``jax.Device.device_kind``;
+a kind that is not in the table is an error, never a default.
 
   compute term    = HLO_FLOPs / peak_flops           (per-device HLO)
   memory term     = HLO_bytes / hbm_bw
@@ -21,9 +21,35 @@ import math
 from repro.analysis.hlo import HloCost, analyze_hlo, sxs_buffer_bytes
 from repro.configs.base import ModelConfig, ShapeConfig
 
-PEAK_FLOPS = 197e12  # bf16 / chip
-HBM_BW = 819e9  # bytes/s
-LINK_BW = 50e9  # bytes/s per ICI link (conservative single-link)
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    flops: float  # bf16 FLOP/s per chip
+    hbm_bw: float  # bytes/s per chip
+    link_bw: float  # bytes/s per ICI link
+
+
+#: ``device_kind`` JAX reports for a TPU v5e chip
+V5E = "TPU v5 lite"
+
+#: Published per-chip peaks by ``device_kind``.  TPU v5e: Google Cloud
+#: documentation, "TPU v5e" — 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s,
+#: 1,600 Gbit/s chip-to-chip interconnect (200 GB/s over 4 links, so
+#: 50 GB/s per link).
+PEAKS = {
+    V5E: ChipPeaks(flops=197e12, hbm_bw=819e9, link_bw=50e9),
+}
+
+
+def peaks_for(device_kind: str) -> ChipPeaks:
+    """The published peaks of ``device_kind``; raises on an unknown kind."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; add them "
+            f"to repro.analysis.roofline.PEAKS with their source"
+        ) from None
 
 
 @dataclasses.dataclass
@@ -79,6 +105,7 @@ def derive(
     cost: dict[str, float],
     hlo_text: str,
     num_devices: int,
+    device_kind: str,
 ) -> Roofline:
     # NOTE: cost_analysis() on the CPU backend counts while-loop bodies once
     # (see analysis/hlo.py header), so all three terms come from the
@@ -86,9 +113,10 @@ def derive(
     coll = analyze_hlo(hlo_text)
     flops = coll.flops
     bytes_accessed = coll.bytes
-    compute_s = flops / PEAK_FLOPS
-    memory_s = bytes_accessed / HBM_BW
-    collective_s = coll.total_wire_bytes / LINK_BW
+    peaks = peaks_for(device_kind)
+    compute_s = flops / peaks.flops
+    memory_s = bytes_accessed / peaks.hbm_bw
+    collective_s = coll.total_wire_bytes / peaks.link_bw
     terms = {"compute": compute_s, "memory": memory_s, "collective": collective_s}
     dominant = max(terms, key=terms.get)
     mf = model_flops(cfg, shape, num_params, active_params(cfg, num_params))
@@ -97,7 +125,7 @@ def derive(
     score_bytes = sxs_buffer_bytes(hlo_text)
     return Roofline(
         attn_score_bytes=score_bytes,
-        memory_s_flash=max(bytes_accessed - score_bytes, 0.0) / HBM_BW,
+        memory_s_flash=max(bytes_accessed - score_bytes, 0.0) / peaks.hbm_bw,
         flops_per_device=flops,
         bytes_per_device=bytes_accessed,
         collectives=coll.as_dict(),
@@ -108,5 +136,5 @@ def derive(
         model_flops_per_device=mf_dev,
         useful_flops_fraction=mf_dev / flops if flops else 0.0,
         step_time_s=step,
-        mfu=(mf_dev / PEAK_FLOPS) / step if step > 0 else 0.0,
+        mfu=(mf_dev / peaks.flops) / step if step > 0 else 0.0,
     )

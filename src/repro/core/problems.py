@@ -4,7 +4,8 @@
       R(V) = 1/2 ||V||_F^2,   f_i(V) = 1/2 ||x_i - x_i V V^T||^2,
   with G = Gram-Schmidt orthonormalization.  The block subgradient only needs
   the Gram product  A_b V = X_b^T (X_b V)  — the paper's Eq. (3) hot spot,
-  served by ``kernels/gram_matvec`` on TPU and jnp on CPU:
+  served by the reduce form in ``kernels/ref`` (XLA) or ``kernels/block_sub``
+  (Pallas):
       ∇_V Σ_{i∈b} f_i = -2 A_b V + A_b V (V^T V) + V (V^T A_b V).
 * :class:`LogisticRegressionProblem` — L2-regularized logistic regression on
   HIGGS-like data:  f_i(V) = log(1 + exp(-b_i x_i^T V)) / n,
@@ -34,14 +35,15 @@ across different pad widths.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from collections.abc import Callable
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
-from repro.kernels import block_sub
+from repro.kernels import block_sub, ref
+from repro.precision import x64
 
 
 class FiniteSumProblem:
@@ -130,7 +132,7 @@ class FiniteSumProblem:
 
     def _call_sub_kernel(self, V_stack, starts, widths, pad_width: int):
         k = self.fused_kernels()
-        with enable_x64():
+        with x64():
             out = k.sub_blocks_jit(
                 jnp.asarray(V_stack),
                 jnp.asarray(starts),
@@ -162,7 +164,7 @@ class FiniteSumProblem:
         reductions and break batch invariance on CPU).
         """
         k = self.fused_kernels()
-        with enable_x64():
+        with x64():
             return np.asarray(k.suboptimality_jit(jnp.asarray(V_stack)))
 
     #: ops per sample row (set by subclasses; the static cost constant must
@@ -260,6 +262,20 @@ def _pad_pow2(Vb, starts, widths):
     )
 
 
+def _packed_rows(X, y=None):
+    """Zero-argument getter of ``block_sub.pack_rows(X, y)``, built on the
+    first Pallas call and kept: the XLA backend never pays for the
+    lane-padded copy.  Evaluated eagerly even when first reached from
+    inside the fused scan's trace, so the table is one device constant."""
+
+    @functools.cache
+    def rows():
+        with jax.ensure_compile_time_eval():
+            return block_sub.pack_rows(X, y)
+
+    return rows
+
+
 # ---------------------------------------------------------------------------
 # PCA (power-method family) on a genomics-like sparse binary matrix
 # ---------------------------------------------------------------------------
@@ -304,7 +320,7 @@ class PCAProblem(FiniteSumProblem):
         self.num_samples = int(self.X.shape[0])
         self.dim = int(self.X.shape[1])
         self.cost_per_row = 2.0 * self.dim * self.k
-        with enable_x64():
+        with x64():
             self._Xj = jnp.asarray(self.X)
             self._X64 = jnp.asarray(self.X, dtype=jnp.float64)
         # reference optimum: exact top-k eigendecomposition of X^T X
@@ -335,24 +351,25 @@ class PCAProblem(FiniteSumProblem):
             # computation of paper Eq. (3).  With eta = 1 the GD update
             # V - (V - A V) = A V followed by Gram-Schmidt *is* the power
             # method, as stated in §7.  Rows past each interval's width are
-            # zero-masked (they contribute 0.0 to both matmuls); bit
+            # zero-masked (they contribute 0.0 to both reductions); bit
             # reproducibility across engines comes from every caller using
             # the same static width_bucket pad per width, NOT from pad-width
-            # invariance — see width_bucket.
+            # invariance — see width_bucket.  The reduce form (one column
+            # at a time, as in logreg) keeps every row batch-invariant.
             Vb, starts, widths, g = _pad_pow2(Vb, starts, widths)
-            idx = jnp.clip(starts[:, None] - 1 + jnp.arange(pad_width)[None, :], 0, n - 1)
-            xg = Xj[idx]  # [G, pad, d]
-            mask = (jnp.arange(pad_width)[None, :] < widths[:, None]).astype(Xj.dtype)
-            xg = xg * mask[:, :, None]
-            return (-(jnp.swapaxes(xg, 1, 2) @ (xg @ Vb)))[:g]
+            return ref.block_sub_pca_ref(Xj, Vb, starts, widths, pad_width)[:g]
+
+        rows = _packed_rows(Xj)
 
         def sub_blocks_pallas(Vb, starts, widths, pad_width: int, interpret: bool):
             # same _pad_pow2 batching as the XLA form, then one Pallas
             # program per task evaluating the identical expression (see
             # kernels/block_sub.py for the bit-exactness contract)
+            if pad_width > block_sub.MAX_WINDOW_ROWS:
+                return sub_blocks(Vb, starts, widths, pad_width)
             Vb, starts, widths, g = _pad_pow2(Vb, starts, widths)
             return block_sub.pca_block_sub(
-                Xj, Vb, starts, widths, pad_width, interpret=interpret
+                rows(), Vb, starts, widths, pad_width, interpret=interpret
             )[:g]
 
         def explained_one(V):
@@ -400,12 +417,12 @@ class PCAProblem(FiniteSumProblem):
         # batched engine, and the fused scan all orthonormalize with the
         # exact same bits
         k = self.fused_kernels()
-        with enable_x64():
+        with x64():
             return np.asarray(k.project_jit(jnp.asarray(V_stack)))
 
     def explained_variance(self, V: np.ndarray) -> float:
         self.fused_kernels()
-        with enable_x64():
+        with x64():
             return float(self._explained_jit(jnp.asarray(V)[None])[0])
 
     # compute_cost doc: c = 2 ζ d k rows with ζ the density (paper §3); for
@@ -448,7 +465,7 @@ class LogisticRegressionProblem(FiniteSumProblem):
         self.cost_per_row = 2.0 * self.dim
         if self.lam is None:
             self.lam = 1.0 / self.num_samples
-        with enable_x64():
+        with x64():
             self._Xj = jnp.asarray(self.X)
             self._yj = jnp.asarray(self.y)
             self._X64 = jnp.asarray(self.X, dtype=jnp.float64)
@@ -477,19 +494,16 @@ class LogisticRegressionProblem(FiniteSumProblem):
             # width_bucket pad — the reduction is NOT invariant to the pad
             # length itself (see width_bucket).
             Vb, starts, widths, g = _pad_pow2(Vb, starts, widths)
-            idx = jnp.clip(starts[:, None] - 1 + jnp.arange(pad_width)[None, :], 0, n - 1)
-            xg = Xj[idx]  # [G, pad, d]
-            yg = yj[idx] * (jnp.arange(pad_width)[None, :] < widths[:, None]).astype(
-                yj.dtype
-            )
-            z = yg * jnp.sum(xg * Vb[:, None, :], axis=2)
-            s = jax.nn.sigmoid(-z)
-            return (-jnp.sum(xg * (yg * s)[:, :, None], axis=1) / n)[:g]
+            return ref.block_sub_logreg_ref(Xj, yj, Vb, starts, widths, pad_width)[:g]
+
+        rows = _packed_rows(Xj, yj)
 
         def sub_blocks_pallas(Vb, starts, widths, pad_width: int, interpret: bool):
+            if pad_width > block_sub.MAX_WINDOW_ROWS:
+                return sub_blocks(Vb, starts, widths, pad_width)
             Vb, starts, widths, g = _pad_pow2(Vb, starts, widths)
             return block_sub.logreg_block_sub(
-                Xj, yj, Vb, starts, widths, pad_width, interpret=interpret
+                rows(), Vb, starts, widths, pad_width, interpret=interpret
             )[:g]
 
         def objective_one(V):
@@ -531,7 +545,7 @@ class LogisticRegressionProblem(FiniteSumProblem):
         """[S] objectives through the shared JAX kernel (one dispatch)."""
         if not hasattr(self, "_objective_jit"):  # set mid-build by fused_kernels
             self.fused_kernels()
-        with enable_x64():
+        with x64():
             return np.asarray(self._objective_jit(jnp.asarray(V_stack)))
 
     def _solve_optimum(self) -> np.ndarray:

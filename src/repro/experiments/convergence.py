@@ -38,6 +38,7 @@ import dataclasses
 import time
 from collections.abc import Sequence
 
+import jax
 import numpy as np
 
 from repro.cluster.simulator import (
@@ -54,6 +55,7 @@ from repro.cluster.simulator import (
 from repro.core.gradient_cache import BatchedGradientCache, scenario_ranks
 from repro.core.problems import FiniteSumProblem
 from repro.experiments.engine import (
+    CAP_AUTO_NO_HOST,
     CAP_PALLAS_HOST,
     EngineCapability,
     EngineCapabilityError,
@@ -82,6 +84,7 @@ class ConvergenceBatchResult:
     repartition_events: list[list[float]]  # per scenario
     evictions: np.ndarray  # [S]
     rejected_stale: np.ndarray  # [S]
+    engine: str = "host"  # the engine that ran: "scan" or "host"
 
     @property
     def num_scenarios(self) -> int:
@@ -145,7 +148,8 @@ def run_convergence_batch(
       iteration per training iteration, batched kernels inside; the
       device mesh does not apply here).
     * ``kind="auto"`` (default) — ``"scan"`` unless the capability report
-      says unsupported, which routes to ``"host"``.
+      says unsupported, which routes to ``"host"`` on the CPU and raises
+      ``CAP_AUTO_NO_HOST`` on an accelerator (never a silent host run).
 
     Legacy ``engine="auto"|"scan"|"host"`` strings still work as
     deprecated aliases (``DeprecationWarning``).  ``eval_every`` defaults
@@ -167,6 +171,18 @@ def run_convergence_batch(
         cap = scan_capability(
             problem, config, traces.num_workers, slot_budget=eng.slot_budget
         )
+        if not cap.supported and jax.default_backend() != "cpu":
+            raise EngineCapabilityError(
+                EngineCapability(
+                    supported=False,
+                    code=CAP_AUTO_NO_HOST,
+                    detail=(
+                        f"kind='auto' will not route to the host engine on "
+                        f"{jax.default_backend()}: {cap.detail} (pass "
+                        f"EngineConfig(kind='host') to run it on the host)"
+                    ),
+                )
+            )
         kind = "scan" if cap.supported else "host"
     if kind == "host" and eng.kernel_backend == "pallas":
         # the host loop drives the problem's numpy wrappers — there is no

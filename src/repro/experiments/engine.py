@@ -44,6 +44,16 @@ CAP_PALLAS_DTYPE = "pallas-unsupported-dtype"
 #: kernel_backend="pallas" requested together with the host engine, which
 #: drives the problem's numpy wrappers and never takes the Pallas path
 CAP_PALLAS_HOST = "pallas-requires-scan-engine"
+#: kernel_backend="pallas" requested for a grid-cache config on an
+#: accelerator: the grid-cache kernel's state is float64/int64, which
+#: XLA:TPU refuses inside a Pallas call (ROADMAP 1.3 moves it to f32/i32)
+CAP_PALLAS_X64_STATE = "pallas-grid-cache-needs-x64"
+#: kind="auto" on an accelerator for a config the scan cannot run: the
+#: host engine is never chosen silently there (ask for kind="host")
+CAP_AUTO_NO_HOST = "auto-never-routes-to-host-on-accelerator"
+#: a §6 load-balanced config on an accelerator: its scan body compiles
+#: for a TPU v5e, but no run of it has finished there (ROADMAP 2.1)
+CAP_LB_ACCELERATOR = "lb-scan-unfinished-on-accelerator"
 
 _KINDS = ("auto", "scan", "host")
 _KERNEL_BACKENDS = ("xla", "pallas")
@@ -56,7 +66,8 @@ class EngineConfig:
     ``kind`` selects the implementation (``"scan"`` — the fused
     ``jax.lax.scan`` engine, ``"host"`` — the numpy-driven batched loop,
     ``"auto"`` — scan unless :func:`repro.experiments.fused.scan_capability`
-    reports the config unsupported).
+    reports the config unsupported, which routes to the host engine on
+    the CPU and raises :class:`EngineCapabilityError` on an accelerator).
 
     ``num_devices`` / ``mesh`` shard the *scenario axis* of the fused scan
     over devices via ``shard_map`` (see
@@ -78,8 +89,9 @@ class EngineConfig:
     paths (the §3 block-subgradient gather and the §5 grid-cache event
     application): ``"xla"`` — the jnp forms (default), ``"pallas"`` — the
     ``repro.kernels`` Pallas twins (``interpret=True`` on CPU so CI
-    exercises the path everywhere; compiled on TPU).  Results are pinned
-    bit-exact across backends on the same platform.
+    exercises the path everywhere; compiled on TPU, where the grid-cache
+    kernel is refused until its state leaves float64).  Results are pinned
+    bit-exact across backends on the CPU.
     """
 
     kind: str = "auto"

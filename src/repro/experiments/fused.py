@@ -81,7 +81,6 @@ import warnings
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 from jax.sharding import PartitionSpec as P
 
 from repro.cluster.simulator import (
@@ -102,6 +101,8 @@ from repro.experiments.engine import (
     CAP_OK,
     CAP_PALLAS_DTYPE,
     CAP_PALLAS_UNAVAILABLE,
+    CAP_LB_ACCELERATOR,
+    CAP_PALLAS_X64_STATE,
     CAP_TILED,
     EngineCapability,
     EngineCapabilityError,
@@ -112,6 +113,7 @@ from repro.kernels.cache_events import grid_cache_update
 from repro.latency.model import FleetTraces, comp_latency_expr
 from repro.lb import jit_optimizer as jlb
 from repro.lb.partitioner import p_start, p_stop
+from repro.precision import x64
 
 #: default budget on densely resident §6 slot-universe entries (per-slot
 #: float64 value buffers are the fused engine's memory trade-off).
@@ -1420,8 +1422,6 @@ def _scan_jit_for(kernels: FusedKernels, mesh=None):
         if mesh is None:
             fn = jax.jit(_run_scan, static_argnums=(0, 1))
         else:
-            from jax.experimental.shard_map import shard_map
-
             repl, data = P(), P("data")
             in_specs = (repl,) * 5 + (
                 data, data, repl, data, data, data, data, repl,
@@ -1430,12 +1430,14 @@ def _scan_jit_for(kernels: FusedKernels, mesh=None):
 
             def sharded(kernels_, spec_, *arrays):
                 body = functools.partial(_run_scan, kernels_, spec_)
-                # check_rep=False: jax 0.4.x has no replication rule for
-                # while_loop (the §6 aligner), and every output here is
-                # data-sharded anyway, so the static check buys nothing.
-                return shard_map(
+                # check_vma=False: every output is data-sharded, so the
+                # varying-manual-axes check has nothing to prove; left on,
+                # it rejects every loop whose carry starts replicated and
+                # turns sharded (the §5 rank walks add replicated slot
+                # widths to sharded counters) unless each gets a pvary.
+                return jax.shard_map(
                     body, mesh=mesh, in_specs=in_specs,
-                    out_specs=out_specs, check_rep=False,
+                    out_specs=out_specs, check_vma=False,
                 )(*arrays)
 
             fn = jax.jit(sharded, static_argnums=(0, 1))
@@ -1460,6 +1462,10 @@ def scan_capability(
     * :data:`~repro.experiments.engine.CAP_ACTIVE_SET` — unsupported: even
       the tiled cache's resident entries exceed the budget; route to the
       host engine.
+    * :data:`~repro.experiments.engine.CAP_LB_ACCELERATOR` — unsupported:
+      a §6 config off the CPU.  Its scan body compiles for a TPU v5e, but
+      runs there never got past the first iteration (ROADMAP 2.1); the
+      host engine (``kind="host"``) still runs it.
 
     ``slot_budget`` defaults to :data:`LB_MAX_SLOTS`.  Bounds here are
     cheap overestimates (no universe is built): the dense bound is
@@ -1469,6 +1475,18 @@ def scan_capability(
     never exceeds.
     """
     budget = int(LB_MAX_SLOTS if slot_budget is None else slot_budget)
+    if config.load_balance and jax.default_backend() != "cpu":
+        return EngineCapability(
+            supported=False,
+            code=CAP_LB_ACCELERATOR,
+            detail=(
+                f"the fused scan does not run §6 load-balanced configs on "
+                f"{jax.default_backend()}: the body compiles, but no run has "
+                f"got past its first iteration there (ROADMAP 2.1); pass "
+                f"EngineConfig(kind='host') to run it on the host engine"
+            ),
+            slot_budget=budget,
+        )
     if not (config.load_balance and config.uses_cache):
         return EngineCapability(
             supported=True,
@@ -1551,16 +1569,23 @@ def scan_unsupported_reason(
 
 
 def kernel_backend_capability(
-    problem: FiniteSumProblem, kernel_backend: str = "xla"
+    problem: FiniteSumProblem,
+    kernel_backend: str,
+    config: MethodConfig,
 ) -> EngineCapability:
     """Whether the fused scan can route this problem's hot paths to Pallas.
 
     ``"xla"`` is always supported.  ``"pallas"`` requires the problem to
     publish Pallas twins (``FusedKernels.sub_blocks_pallas``) and a
     float32 in-flight value dtype (the only dtype the kernels are
-    validated for — see ``kernels/block_sub.py``).  Reported codes:
-    :data:`~repro.experiments.engine.CAP_PALLAS_UNAVAILABLE`,
-    :data:`~repro.experiments.engine.CAP_PALLAS_DTYPE`.
+    validated for — see ``kernels/block_sub.py``).  A ``config`` whose
+    cache is the fixed grid (the §5 path the grid-cache kernel takes) is
+    refused off the CPU: that kernel's state is
+    float64/int64, which XLA:TPU does not accept in a Pallas call, and
+    the f32/i32 regime that would lift this is ROADMAP 1.3.  Reported
+    codes: :data:`~repro.experiments.engine.CAP_PALLAS_UNAVAILABLE`,
+    :data:`~repro.experiments.engine.CAP_PALLAS_DTYPE`,
+    :data:`~repro.experiments.engine.CAP_PALLAS_X64_STATE`.
     """
     if kernel_backend != "pallas":
         return EngineCapability(
@@ -1586,6 +1611,19 @@ def kernel_backend_capability(
                 f"kernel_backend='pallas' supports float32 in-flight "
                 f"values only; {type(problem).__name__} declares "
                 f"{np.dtype(kernels.value_dtype).name}"
+            ),
+        )
+    grid_cache = config.uses_cache and not config.load_balance
+    if grid_cache and jax.default_backend() != "cpu":
+        return EngineCapability(
+            supported=False,
+            code=CAP_PALLAS_X64_STATE,
+            detail=(
+                f"kernel_backend='pallas' cannot run the {config.name} grid "
+                f"cache on {jax.default_backend()}: the grid-cache kernel's "
+                f"state is float64/int64, which XLA:TPU refuses inside a "
+                f"Pallas call (ROADMAP 1.3, an f32/i32 regime, lifts this); "
+                f"use kernel_backend='xla'"
             ),
         )
     return EngineCapability(
@@ -1623,7 +1661,7 @@ def prepare_scan_inputs(
     )
     if not cap.supported:
         raise EngineCapabilityError(cap)
-    kcap = kernel_backend_capability(problem, kernel_backend)
+    kcap = kernel_backend_capability(problem, kernel_backend, config)
     if not kcap.supported:
         raise EngineCapabilityError(kcap)
     # resolve the interpret decision NOW, outside any trace: reading
@@ -1677,7 +1715,7 @@ def prepare_scan_inputs(
             return a
         return np.concatenate([a, np.repeat(a[-1:], pad, axis=0)], axis=0)
 
-    with enable_x64():
+    with x64():
         empty = jnp.zeros((S + pad, traces.num_workers, 0))
         has_b = traces.has_bursts
         trace_args = (
@@ -1781,7 +1819,7 @@ def run_convergence_scan(
         pad=pad,
         kernel_backend=eng.kernel_backend,
     )
-    with enable_x64():
+    with x64():
         outs = _scan_jit_for(kernels, mesh)(kernels, spec, *scan_args)
         times, subopt, fresh, lat, rejected, evictions, published = (
             np.asarray(o)[:S] for o in outs
@@ -1798,4 +1836,5 @@ def run_convergence_scan(
         repartition_events=repartition_events,
         evictions=np.asarray(evictions, dtype=np.int64),
         rejected_stale=np.asarray(rejected, dtype=np.int64),
+        engine="scan",
     )

@@ -20,9 +20,11 @@ results match the XLA path bit for bit (pinned by tests and the bench
 kernel-backend tier).
 
 Dtypes are taken from the operands (the engine's cache state is
-float64/int64); interpret mode executes them exactly.  A real-TPU
-deployment needs the f32/i32 state migration ROADMAP tracks — this
-kernel is validated in interpret mode only.
+float64/int64); interpret mode on the CPU executes them exactly.  XLA:TPU
+refuses 64-bit operands in a Pallas call (and the ``(1, R)`` / ``(1,)``
+blocks are not tile-aligned), so off the CPU the engine refuses this
+kernel (``CAP_PALLAS_X64_STATE``) until the f32/i32 regime of ROADMAP 1.3
+lands.
 """
 
 from __future__ import annotations
@@ -30,11 +32,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-
-def _scalar(ref, j):
-    """One scalar from a [1, R] block at dynamic column ``j``."""
-    return pl.load(ref, (pl.dslice(0, 1), pl.dslice(j, 1)))[0, 0]
 
 
 def _grid_cache_kernel(
@@ -62,14 +59,12 @@ def _grid_cache_kernel(
 
     def rank_body(j, carry):
         sums, covered, rejected = carry
-        valid = _scalar(valid_ref, j)
-        slot = _scalar(slot_ref, j)
-        tag = _scalar(tag_ref, j)
-        v = pl.load(vals_ref, (pl.dslice(0, 1), pl.dslice(j, 1), slice(None)))[0, 0]
-        cur_it = _scalar(iters_ref, slot)
-        old = pl.load(
-            values_ref, (pl.dslice(0, 1), pl.dslice(slot, 1), slice(None))
-        )[0, 0]
+        valid = valid_ref[0, j]
+        slot = slot_ref[0, j]
+        tag = tag_ref[0, j]
+        v = vals_ref[0, j, :]
+        cur_it = iters_ref[0, slot]
+        old = values_ref[0, slot, :]
         # staleness dominance + in-place update — the same expressions as
         # the XLA rank_body in fused._apply_cache_events, scenario-local
         active = cur_it >= 0
@@ -78,17 +73,9 @@ def _grid_cache_kernel(
         rej = valid & dom
         delta = v - jnp.where(active, old, 0.0)
         sums = jnp.where(acc, sums + delta, sums)
-        pl.store(
-            values_ref,
-            (pl.dslice(0, 1), pl.dslice(slot, 1), slice(None)),
-            jnp.where(acc, v, old)[None, None],
-        )
-        pl.store(
-            iters_ref,
-            (pl.dslice(0, 1), pl.dslice(slot, 1)),
-            jnp.where(acc, tag, cur_it)[None, None],
-        )
-        sw = pl.load(width_ref, (pl.dslice(slot, 1),))[0]
+        values_ref[0, slot, :] = jnp.where(acc, v, old)
+        iters_ref[0, slot] = jnp.where(acc, tag, cur_it)
+        sw = width_ref[slot]
         covered = covered + jnp.where(acc & ~active, sw, 0)
         rejected = rejected + rej.astype(rejected.dtype)
         return sums, covered, rejected

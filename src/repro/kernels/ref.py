@@ -6,6 +6,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 
 def gram_matvec_ref(x: jnp.ndarray, v: jnp.ndarray) -> jnp.ndarray:
@@ -30,18 +31,55 @@ def dsag_update_ref(
     return new_c.astype(c.dtype), new_h
 
 
+def tree_sum(a, axis: int):
+    """Sum over ``axis`` in a fixed pairwise order; the axis is kept at
+    length 1.
+
+    XLA:CPU picks the association of a reduction over a non-minor axis
+    per shape and per fusion: the pad rows of a ``[G, pad, d]`` batch and
+    the same task's rows reduced alone inside a Pallas program can round
+    differently.  So the block subgradients sum their rows with an
+    explicit halving tree of elementwise adds, which fixes every output's
+    association whatever the batch, the fusion or the backend.  (The
+    reductions over the minor ``d`` axis stay ``jnp.sum``: XLA rewrites a
+    halving tree of lane slices back into a reduce in some fusions and not
+    in others, while its minor-axis reduce is batch-invariant as is.)  An
+    odd length folds its last element into element 0 (adding 0.0
+    elsewhere).
+    """
+    m = a.shape[axis]
+    while m > 1:
+        h = m // 2
+        s = lax.slice_in_dim(a, 0, h, axis=axis) + lax.slice_in_dim(a, h, 2 * h, axis=axis)
+        if m % 2:
+            first = lax.broadcasted_iota(jnp.int32, s.shape, axis) == 0
+            s = s + jnp.where(first, lax.slice_in_dim(a, 2 * h, m, axis=axis), 0.0)
+        a, m = s, h
+    return a
+
+
 def block_sub_pca_ref(x, Vb, starts, widths, pad_width: int):
     """§3 PCA block subgradients, clip-gather jnp form (block_sub twin).
 
-    x: [n, d], Vb: [G, d, k], starts/widths: [G] -> [G, d, k].  The same
+    x: [n, d], Vb: [G, d, k], starts/widths: [G] -> [G, d, k].  The
     expression ``PCAProblem.sub_blocks`` evaluates (pre-batch-padding).
+    Each of the k output columns is one elementwise multiply and
+    reduction over ``d``, then a :func:`tree_sum` over the pad rows (the
+    logreg form below), rather than a batched matmul: XLA lowers a ``[G, pad, d]``
+    batched product with an accumulation order that depends on G, whereas
+    this form gives every row the same bits whatever else shares the
+    batch.
     """
     n = x.shape[0]
     idx = jnp.clip(starts[:, None] - 1 + jnp.arange(pad_width)[None, :], 0, n - 1)
     xg = x[idx]  # [G, pad, d]
     mask = (jnp.arange(pad_width)[None, :] < widths[:, None]).astype(x.dtype)
     xg = xg * mask[:, :, None]
-    return -(jnp.swapaxes(xg, 1, 2) @ (xg @ Vb))
+    cols = []
+    for j in range(Vb.shape[2]):
+        xv = jnp.sum(xg * Vb[:, None, :, j], axis=2, keepdims=True)  # [G, pad, 1]
+        cols.append(-tree_sum(xg * xv, axis=1)[:, 0])  # [G, d]
+    return jnp.stack(cols, axis=2)
 
 
 def block_sub_logreg_ref(x, y, Vb, starts, widths, pad_width: int):
@@ -57,7 +95,7 @@ def block_sub_logreg_ref(x, y, Vb, starts, widths, pad_width: int):
     yg = y[idx] * (jnp.arange(pad_width)[None, :] < widths[:, None]).astype(y.dtype)
     z = yg * jnp.sum(xg * Vb[:, None, :], axis=2)
     s = jax.nn.sigmoid(-z)
-    return -jnp.sum(xg * (yg * s)[:, :, None], axis=1) / n
+    return -tree_sum(xg * (yg * s)[:, :, None], axis=1)[:, 0] / n
 
 
 def grid_cache_update_ref(

@@ -117,7 +117,7 @@ class MomentBuffer:
         (per-scenario, optional) drops samples recorded before it — the
         churn re-profiling cutoff (see
         :func:`repro.lb.jit_optimizer.window_moments`)."""
-        from jax.experimental import enable_x64
+        from repro.precision import x64
 
         from repro.lb.jit_optimizer import PROFILER_WINDOW, _window_moments_jitted
 
@@ -128,7 +128,7 @@ class MomentBuffer:
         args = (self.t_rec, self.comm, self.comp, self.valid, np.asarray(now))
         if since is not None:
             args = args + (np.asarray(since),)
-        with enable_x64():
+        with x64():
             out = fn(*args)
         e_comm, v_comm, e_comp, v_comp, cnt = (np.asarray(a) for a in out)
         return e_comm, v_comm, e_comp, v_comp, cnt

@@ -1,11 +1,15 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Multi-pod dry-run: lower + compile every (arch x shape) cell on the
 production mesh and extract memory / cost / collective analyses.
 
-The two lines above MUST stay first: jax locks the device count on first
-initialization, and the production meshes need 512 host devices.
+The three lines above MUST stay first: jax locks the platform and the
+device count on first initialization, and the production meshes need 512
+host devices.  The platform is pinned to the CPU so that, on a machine with
+an accelerator, neither this process nor the per-cell children it spawns
+take the chip.
 
   one cell:  PYTHONPATH=src python -m repro.launch.dryrun \
                  --arch qwen2-7b --shape train_4k [--multi-pod]
@@ -222,7 +226,10 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, overrides=None) -> dic
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
     hlo = compiled.as_text()
-    rl = roofline_mod.derive(cfg, shape, nparams, cost, hlo, mesh.devices.size)
+    # the fake host mesh stands in for a v5e pod: project onto v5e peaks
+    rl = roofline_mod.derive(
+        cfg, shape, nparams, cost, hlo, mesh.devices.size, roofline_mod.V5E
+    )
 
     result = {
         "arch": arch,
@@ -275,6 +282,7 @@ def run_all(multi_pod: bool, force: bool = False) -> int:
                 "--arch", arch, "--shape", shape_name,
             ] + (["--multi-pod"] if multi_pod else [])
             print(f"[dryrun] {arch} x {shape_name} ({'2x16x16' if multi_pod else '16x16'}) ...", flush=True)
+            # the child inherits the CPU pin set at the top of this module
             proc = subprocess.run(cmd, capture_output=True, text=True, timeout=3600)
             if proc.returncode != 0:
                 failures += 1

@@ -9,16 +9,12 @@ from __future__ import annotations
 
 import jax
 
-# jax.sharding.AxisType landed after 0.4.x; on older jax every mesh axis is
-# implicitly Auto, which is exactly what we request on newer versions — so
-# the fallback just omits the kwarg.
-_AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
-
-
 def _make_mesh(shape, axes):
-    if _AXIS_TYPE is not None:
-        return jax.make_mesh(shape, axes, axis_types=(_AXIS_TYPE.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    # Auto axes: the sharding is left to the partitioner, as every caller
+    # here expects (jax.make_mesh otherwise defaults to Explicit axes).
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -40,6 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint import CheckpointManager
+from repro.compile_cache import enable_compile_cache
 from repro.configs import TrainConfig, get_config, get_smoke_config
 from repro.core.dsag_pjit import (
     GroupSpec,
@@ -354,6 +355,7 @@ def main(argv: list[str] | None = None) -> None:
         help="assert ξ reached 1.0 and the loss decreased (CI smoke gate)",
     )
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.arch in PAPER_ARCHES:
         lr = args.lr if args.lr != 3e-4 else 0.25  # paper-scale step size
         tc = paper_train_config(lr, dsag=not args.no_dsag)

@@ -130,21 +130,34 @@ def _wilson_hilferty_gamma(z, shape, scale):
     return jnp.maximum(x, 1e-12)
 
 
-def _draw_what_if(key, e_y, v_y, e_z, v_z, K: int):
+def _normal_base(key, N: int, K: int, dtype):
+    """The two ``[N, K]`` standard-normal base draws (comm, comp).
+
+    They depend only on the key and the shape, so Algorithm 1 draws them
+    once per call and every h(p') round reuses them: the float64 normal
+    sampler is most of what one h evaluation costs to compile on a TPU,
+    where float64 is emulated, and the hill-climb holds four evaluations.
+    """
+    k_comm, k_comp = jax.random.split(key)
+    return (
+        jax.random.normal(k_comm, (N, K), dtype=dtype),
+        jax.random.normal(k_comp, (N, K), dtype=dtype),
+    )
+
+
+def _draw_what_if(z, e_y, v_y, e_z, v_z):
     """[S, N, K] what-if latency draws (comm, comp).
 
-    One ``[N, K]`` standard-normal base draw per component, shared by
-    every scenario (the batched counterpart of the host optimizer's
-    historical per-scenario ``default_rng(seed)`` streams, which also
-    shared one underlying uniform stream), pushed through the
-    Wilson–Hilferty gamma transform with each scenario's own moments.  A
-    scenario's draws therefore depend only on its parameters — never on
-    its row position or on which scenarios share the batch.
+    One ``[N, K]`` standard-normal base draw per component (``z``, from
+    :func:`_normal_base`), shared by every scenario (the batched
+    counterpart of the host optimizer's historical per-scenario
+    ``default_rng(seed)`` streams, which also shared one underlying
+    uniform stream), pushed through the Wilson–Hilferty gamma transform
+    with each scenario's own moments.  A scenario's draws therefore depend
+    only on its parameters — never on its row position or on which
+    scenarios share the batch.
     """
-    N = e_y.shape[-1]
-    k_comm, k_comp = jax.random.split(key)
-    z_comm = jax.random.normal(k_comm, (N, K), dtype=e_y.dtype)
-    z_comp = jax.random.normal(k_comp, (N, K), dtype=e_y.dtype)
+    z_comm, z_comp = z
     comm = _wilson_hilferty_gamma(
         z_comm[None], (e_y * e_y / v_y)[:, :, None], (v_y / e_y)[:, :, None]
     )
@@ -225,12 +238,24 @@ def estimate_h(
     really is uncovered), which is exactly the signal Algorithm 1 reacts
     to.  An all-True mask is value-identical to ``alive=None``.
     """
+    z = _normal_base(key, e_comm.shape[-1], K, e_comm.dtype)
+    return _estimate_h_from(
+        z, e_comm, v_comm, e_comp, v_comp, n_j, p_cur, p_new,
+        w=w, margin=margin, K=K, alive=alive,
+    )
+
+
+def _estimate_h_from(
+    z, e_comm, v_comm, e_comp, v_comp, n_j, p_cur, p_new, *, w: int,
+    margin: float, K: int, alive=None,
+):
+    """:func:`estimate_h` on given base draws ``z`` (see :func:`_normal_base`)."""
     e_y = jnp.maximum(e_comm, 1e-12)
     v_y = jnp.maximum(v_comm, 1e-18)
     ratio = p_cur / p_new
     e_z = jnp.maximum(e_comp * ratio, 1e-12)
     v_z = jnp.maximum(v_comp * ratio * ratio, 1e-18)
-    comm, comp = _draw_what_if(key, e_y, v_y, e_z, v_z, K)
+    comm, comp = _draw_what_if(z, e_y, v_y, e_z, v_z)
     if alive is not None:
         comm = jnp.where(alive[:, :, None], comm, jnp.inf)
     u = _what_if_replay(comm, comp, w, K, margin, alive=alive)
@@ -300,11 +325,12 @@ def algorithm1(
     S, N = p_cur.shape
     rows = jnp.arange(S)
     eff, idx_cap = ladder_tables(ladder, n_j)
+    z = _normal_base(key, N, K, e_comm.dtype)
 
     def h_of(p_new):
-        return estimate_h(
-            e_comm, v_comm, e_comp, v_comp, n_j, p_cur, p_new,
-            w=w, margin=margin, key=key, K=K, alive=alive,
+        return _estimate_h_from(
+            z, e_comm, v_comm, e_comp, v_comp, n_j, p_cur, p_new,
+            w=w, margin=margin, K=K, alive=alive,
         )
 
     def only_alive(x):  # mask for max/argmax reductions

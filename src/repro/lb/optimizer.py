@@ -34,10 +34,10 @@ from collections.abc import Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro.lb import jit_optimizer as jlb
 from repro.lb.partitioner import build_p_ladder
+from repro.precision import x64
 
 
 @dataclasses.dataclass
@@ -136,7 +136,7 @@ class LoadBalanceOptimizer:
         fn = jlb._estimate_h_jitted(
             int(b.w), int(self.sim_iterations), float(b.margin)
         )
-        with enable_x64():
+        with x64():
             h = fn(
                 jnp.asarray(b.e_comm, jnp.float64),
                 jnp.asarray(b.v_comm, jnp.float64),
@@ -186,7 +186,7 @@ class LoadBalanceOptimizer:
             float(inputs.margin),
             with_alive=alive is not None,
         )
-        with enable_x64():
+        with x64():
             args = (
                 jnp.asarray(p, jnp.float64),
                 jnp.asarray(inputs.e_comm, jnp.float64),
@@ -234,7 +234,7 @@ class LoadBalanceOptimizer:
     ) -> np.ndarray:
         """[S] bool: Eq.-(7) objective improves by > improvement_threshold."""
         fn = jlb._should_publish_jitted(float(self.improvement_threshold))
-        with enable_x64():
+        with x64():
             out = fn(
                 jnp.asarray(p, jnp.float64),
                 jnp.asarray(p_new, jnp.float64),

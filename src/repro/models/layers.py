@@ -63,6 +63,10 @@ class ParamDecl:
         assert len(self.shape) == len(self.logical), (self.shape, self.logical)
 
 
+#: logical axes that stack independent weight matrices (never a fan-in)
+_STACK_AXES = ("layers", "expert")
+
+
 def _init_leaf(decl: ParamDecl, key, dtype) -> jnp.ndarray:
     dt = jnp.dtype(decl.dtype or dtype)
     if decl.init == "zeros":
@@ -70,7 +74,9 @@ def _init_leaf(decl: ParamDecl, key, dtype) -> jnp.ndarray:
     if decl.init == "ones":
         return jnp.ones(decl.shape, dt)
     if decl.init == "scaled":
-        fan_in = decl.shape[0] if len(decl.shape) > 1 else decl.shape[0]
+        # fan-in is the first dim that is not a stacking axis: a scanned
+        # layer stack or an expert bank must not set the scale
+        fan_in = next(n for n, ax in zip(decl.shape, decl.logical) if ax not in _STACK_AXES)
         std = 1.0 / math.sqrt(max(fan_in, 1))
         return (jax.random.normal(key, decl.shape, jnp.float32) * std).astype(dt)
     if decl.init == "normal":

@@ -116,12 +116,12 @@ class TestRoofline:
     SHAPE = ShapeConfig("train_4k", 4096, 256, "train")
 
     def test_dominant_term_collective(self, text):
-        r = roofline.derive(_tiny_model(), self.SHAPE, 1000, {}, text, 4)
+        r = roofline.derive(_tiny_model(), self.SHAPE, 1000, {}, text, 4, roofline.V5E)
         assert r.flops_per_device == TRIPS * DOT_FLOPS
         assert r.bytes_per_device == ENTRY_BYTES
-        assert math.isclose(r.compute_s, TRIPS * DOT_FLOPS / roofline.PEAK_FLOPS)
-        assert math.isclose(r.memory_s, ENTRY_BYTES / roofline.HBM_BW)
-        assert math.isclose(r.collective_s, WIRE_BYTES / roofline.LINK_BW)
+        assert math.isclose(r.compute_s, TRIPS * DOT_FLOPS / roofline.PEAKS[roofline.V5E].flops)
+        assert math.isclose(r.memory_s, ENTRY_BYTES / roofline.PEAKS[roofline.V5E].hbm_bw)
+        assert math.isclose(r.collective_s, WIRE_BYTES / roofline.PEAKS[roofline.V5E].link_bw)
         # the fixture's wire term is the largest of the three
         assert r.dominant == "collective"
         assert r.step_time_s == r.collective_s
@@ -133,19 +133,23 @@ class TestRoofline:
             "all-reduce(%y), replica_groups={{0,1,2,3}}, to_apply=%add",
             "copy(%y)",
         )
-        r = roofline.derive(_tiny_model(), self.SHAPE, 1000, {}, variant, 4)
+        r = roofline.derive(_tiny_model(), self.SHAPE, 1000, {}, variant, 4, roofline.V5E)
         assert r.bytes_per_device == ENTRY_BYTES
         assert r.collective_s == 0.0
         assert r.dominant == "memory"
         assert r.step_time_s == r.memory_s
 
+    def test_unknown_device_kind_raises(self, text):
+        with pytest.raises(ValueError, match="no published peaks"):
+            roofline.derive(_tiny_model(), self.SHAPE, 1000, {}, text, 4, "cpu")
+
     def test_model_flops_and_mfu(self, text):
-        r = roofline.derive(_tiny_model(), self.SHAPE, 1000, {}, text, 4)
+        r = roofline.derive(_tiny_model(), self.SHAPE, 1000, {}, text, 4, roofline.V5E)
         mf = 6.0 * 1000 * 4096 * 256 / 4  # 6ND train, per device
         assert math.isclose(r.model_flops_per_device, mf)
         assert math.isclose(
             r.useful_flops_fraction, mf / (TRIPS * DOT_FLOPS)
         )
         assert math.isclose(
-            r.mfu, (mf / roofline.PEAK_FLOPS) / r.step_time_s
+            r.mfu, (mf / roofline.PEAKS[roofline.V5E].flops) / r.step_time_s
         )

@@ -193,14 +193,16 @@ class TestShardedPallas:
 
 class TestCapabilityReasons:
     def test_xla_always_supported(self, logreg_small):
-        cap = kernel_backend_capability(logreg_small, "xla")
+        cfg = MethodConfig(name="dsag", w=2, eta=0.25, subpartitions=2)
+        cap = kernel_backend_capability(logreg_small, "xla", cfg)
         assert cap.supported
 
     def test_pallas_supported_for_committed_problems(
         self, logreg_small, pca_small
     ):
+        cfg = MethodConfig(name="dsag", w=2, eta=0.25, subpartitions=2)
         for prob in (logreg_small, pca_small):
-            cap = kernel_backend_capability(prob, "pallas")
+            cap = kernel_backend_capability(prob, "pallas", cfg)
             assert cap.supported, cap.detail
 
     def test_problem_without_pallas_kernels(self):
@@ -210,11 +212,11 @@ class TestCapabilityReasons:
         prob = LogisticRegressionProblem(X=X, y=y)
         kernels = prob.fused_kernels()
         prob._kernels = dataclasses.replace(kernels, sub_blocks_pallas=None)
-        cap = kernel_backend_capability(prob, "pallas")
+        cfg = MethodConfig(name="dsag", w=2, eta=0.25, subpartitions=2)
+        cap = kernel_backend_capability(prob, "pallas", cfg)
         assert not cap.supported
         assert cap.code == CAP_PALLAS_UNAVAILABLE
         traces = small_fleet(n_workers=4, n_scenarios=1, horizon=10)
-        cfg = MethodConfig(name="dsag", w=2, eta=0.25, subpartitions=2)
         with pytest.raises(EngineCapabilityError) as ei:
             run_convergence_batch(
                 prob, traces, cfg, 10, seed=0,
@@ -226,7 +228,8 @@ class TestCapabilityReasons:
         prob = PCAProblem(
             X=make_genomics_like_matrix(60, 16, seed=2).astype(np.float64), k=2
         )
-        cap = kernel_backend_capability(prob, "pallas")
+        cfg = MethodConfig(name="dsag", w=2, eta=0.25, subpartitions=2)
+        cap = kernel_backend_capability(prob, "pallas", cfg)
         assert not cap.supported
         assert cap.code == CAP_PALLAS_DTYPE
 

@@ -13,10 +13,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
-from jax.experimental import enable_x64
 
 from repro.kernels import ops, ref
-from repro.kernels.block_sub import logreg_block_sub, pca_block_sub
+from repro.kernels.block_sub import logreg_block_sub, pack_rows, pca_block_sub
 from repro.kernels.cache_events import grid_cache_update
 from repro.kernels.ops import (
     dsag_cache_update_op,
@@ -26,6 +25,7 @@ from repro.kernels.ops import (
     gram_matvec_op,
     gram_matvec_ref,
 )
+from repro.precision import x64
 
 
 class TestGramMatvec:
@@ -269,7 +269,7 @@ class TestBlockSubTwins:
         seed=st.integers(min_value=0, max_value=2**20),
     )
     def test_logreg_bitexact_vs_jitted_ref(self, n, d, g, seed):
-        with enable_x64():
+        with x64():
             key = jax.random.key(seed)
             X, y = self._problem_data(n, d, seed)
             pad = int(min(1 << int(np.random.default_rng(seed).integers(0, 4)), n))
@@ -277,7 +277,7 @@ class TestBlockSubTwins:
             starts = jax.random.randint(k1, (g,), 1, n - pad + 2).astype(jnp.int64)
             widths = jax.random.randint(k2, (g,), 1, pad + 1).astype(jnp.int64)
             Vb = jax.random.normal(k3, (g, d), jnp.float32)
-            got = logreg_block_sub(X, y, Vb, starts, widths, pad, interpret=True)
+            got = logreg_block_sub(pack_rows(X, y), Vb, starts, widths, pad, interpret=True)
             want = _jit_ref(ref.block_sub_logreg_ref, 5)(X, y, Vb, starts, widths, pad)
             np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -290,7 +290,7 @@ class TestBlockSubTwins:
         seed=st.integers(min_value=0, max_value=2**20),
     )
     def test_pca_bitexact_vs_jitted_ref(self, n, d, k, g, seed):
-        with enable_x64():
+        with x64():
             key = jax.random.key(seed)
             X = (jax.random.uniform(key, (n, d)) < 0.3).astype(jnp.float32)
             pad = int(min(1 << int(np.random.default_rng(seed).integers(0, 4)), n))
@@ -298,45 +298,45 @@ class TestBlockSubTwins:
             starts = jax.random.randint(k1, (g,), 1, n - pad + 2).astype(jnp.int64)
             widths = jax.random.randint(k2, (g,), 1, pad + 1).astype(jnp.int64)
             Vb = jax.random.normal(k3, (g, d, k), jnp.float32)
-            got = pca_block_sub(X, Vb, starts, widths, pad, interpret=True)
+            got = pca_block_sub(pack_rows(X), Vb, starts, widths, pad, interpret=True)
             want = _jit_ref(ref.block_sub_pca_ref, 4)(X, Vb, starts, widths, pad)
             np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
     def test_full_range_width(self):
         """pad == n (the gd/coded full-dataset bucket): off = 0, no roll."""
-        with enable_x64():
+        with x64():
             n, d = 50, 7
             X, y = self._problem_data(n, d, 0)
             Vb = jax.random.normal(jax.random.key(1), (2, d), jnp.float32)
             starts = jnp.ones((2,), jnp.int64)
             widths = jnp.full((2,), n, jnp.int64)
-            got = logreg_block_sub(X, y, Vb, starts, widths, n, interpret=True)
+            got = logreg_block_sub(pack_rows(X, y), Vb, starts, widths, n, interpret=True)
             want = _jit_ref(ref.block_sub_logreg_ref, 5)(X, y, Vb, starts, widths, n)
             np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
     def test_single_row_blocks(self):
         """pad == 1 (width-1 intervals): every window is one row."""
-        with enable_x64():
+        with x64():
             n, d = 20, 5
             X, y = self._problem_data(n, d, 3)
             Vb = jax.random.normal(jax.random.key(2), (4, d), jnp.float32)
             starts = jnp.asarray([1, 7, 19, 20], jnp.int64)
             widths = jnp.ones((4,), jnp.int64)
-            got = logreg_block_sub(X, y, Vb, starts, widths, 1, interpret=True)
+            got = logreg_block_sub(pack_rows(X, y), Vb, starts, widths, 1, interpret=True)
             want = _jit_ref(ref.block_sub_logreg_ref, 5)(X, y, Vb, starts, widths, 1)
             np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
     def test_bad_pad_width_rejected(self):
-        with enable_x64():
+        with x64():
             n, d = 20, 5
             X, y = self._problem_data(n, d, 4)
             Vb = jnp.zeros((1, d), jnp.float32)
             idx = jnp.ones((1,), jnp.int64)
             for bad in (0, n + 1):
                 with pytest.raises(ValueError, match="pad_width"):
-                    logreg_block_sub(X, y, Vb, idx, idx, bad, interpret=True)
+                    logreg_block_sub(pack_rows(X, y), Vb, idx, idx, bad, interpret=True)
             with pytest.raises(ValueError, match="pad_width"):
-                pca_block_sub(X, jnp.zeros((1, d, 2)), idx, idx, 0, interpret=True)
+                pca_block_sub(pack_rows(X), jnp.zeros((1, d, 2)), idx, idx, 0, interpret=True)
 
 
 class TestGridCacheUpdateTwin:
@@ -366,7 +366,7 @@ class TestGridCacheUpdateTwin:
         seed=st.integers(min_value=0, max_value=2**20),
     )
     def test_bitexact_vs_jitted_ref(self, S, R, E, F, seed):
-        with enable_x64():
+        with x64():
             args = self._random_case(seed, S, R, E, F)
             got = grid_cache_update(*args, interpret=True)
             want = jax.jit(ref.grid_cache_update_ref)(*args)
@@ -376,7 +376,7 @@ class TestGridCacheUpdateTwin:
     def test_stale_dominated_events_rejected(self):
         """An event older than its slot's resident iteration must bump the
         rejected counter and leave the table untouched."""
-        with enable_x64():
+        with x64():
             S, R, E, F = 1, 1, 2, 3
             valid_r = jnp.ones((S, R), bool)
             slot_r = jnp.zeros((S, R), jnp.int64)

@@ -268,6 +268,62 @@ class TestRouting:
             logreg_small, traces, cfg, 20, seed=0, engine=EngineConfig(kind="host")
         )
         assert_results_equal(auto, host)
+        assert auto.engine == host.engine == "host"
+
+    def test_unsupported_config_auto_refuses_host_on_accelerator(
+        self, logreg_small, monkeypatch
+    ):
+        """Off the CPU, ``kind="auto"`` never runs the host engine unasked."""
+        import jax
+
+        from repro.experiments.engine import (
+            CAP_AUTO_NO_HOST,
+            EngineCapabilityError,
+            EngineConfig,
+        )
+
+        cluster, traces = artificial_fleet(logreg_small)
+        cfg = lb_config("dsag")
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        with pytest.raises(EngineCapabilityError) as exc:
+            run_convergence_batch(
+                logreg_small, traces, cfg, 10, seed=0,
+                engine=EngineConfig(kind="auto", slot_budget=3),
+            )
+        assert exc.value.capability.code == CAP_AUTO_NO_HOST
+        assert "kind='host'" in str(exc.value)
+
+    def test_lb_scan_refused_on_accelerator(self, logreg_small, monkeypatch):
+        """Off the CPU the scan refuses §6 configs with their own code (their
+        body has not finished a run on a TPU), loudly from kind="scan" and
+        from kind="auto"; configs without §6 keep the scan."""
+        import jax
+
+        from repro.experiments.engine import (
+            CAP_AUTO_NO_HOST,
+            CAP_LB_ACCELERATOR,
+            EngineCapabilityError,
+            EngineConfig,
+        )
+
+        cluster, traces = artificial_fleet(logreg_small)
+        cfg = lb_config("dsag")
+        plain = MethodConfig(name="dsag", w=cfg.w, eta=cfg.eta, subpartitions=3)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        cap = fused.scan_capability(logreg_small, cfg, traces.num_workers)
+        assert not cap.supported and cap.code == CAP_LB_ACCELERATOR
+        assert "ROADMAP 2.1" in cap.detail and "kind='host'" in cap.detail
+        assert fused.scan_capability(logreg_small, plain, traces.num_workers).supported
+        with pytest.raises(EngineCapabilityError) as exc:
+            run_convergence_batch(
+                logreg_small, traces, cfg, 10, seed=0,
+                engine=EngineConfig(kind="scan"),
+            )
+        assert exc.value.capability.code == CAP_LB_ACCELERATOR
+        with pytest.raises(EngineCapabilityError) as exc:
+            run_convergence_batch(logreg_small, traces, cfg, 10, seed=0)
+        assert exc.value.capability.code == CAP_AUTO_NO_HOST
+        assert "ROADMAP 2.1" in str(exc.value)
 
     def test_legacy_lb_max_slots_monkeypatch_still_gates(
         self, logreg_small, monkeypatch

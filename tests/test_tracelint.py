@@ -211,9 +211,9 @@ class TestTL004DtypeLeak:
         """A float64 cast leaking out of a kernel declared float32."""
         prob = lint_entries._probe_logreg()
         kernels = prob.fused_kernels()
-        from jax.experimental import enable_x64
+        from repro.precision import x64
 
-        with enable_x64():
+        with x64():
             jaxpr = jax.make_jaxpr(
                 lambda Vb, st, wd: kernels.sub_blocks(Vb, st, wd, 16).astype(
                     jnp.float64
@@ -299,9 +299,9 @@ def _churn_latency_chain(times, sd_rows, unit, cost, factor, start, comm):
 
 
 def _churn_latency_probe() -> EntryProbe:
-    from jax.experimental import enable_x64
+    from repro.precision import x64
 
-    with enable_x64():
+    with x64():
         batches = []
         for seed in (0, 1, 2, 3):
             rng = np.random.default_rng(seed)
