@@ -637,42 +637,45 @@ def run_convergence_sweep(
         burst_duration_mean = (
             regime.duration_mean if burst_duration_mean is None else burst_duration_mean
         )
-    traces = sample_fleet(
-        cluster,
-        n_scenarios,
-        num_iterations,
-        burst_rate=burst_rate,
-        burst_factor_mean=burst_factor_mean,
-        burst_duration_mean=burst_duration_mean,
-        seed=seed + 1,
-    )
-    eng = as_engine_config(engine, _stacklevel=3)
-    results: dict[str, ConvergenceBatchResult] = {}
-    t0 = time.perf_counter()
-    for name, cfg in methods.items():
-        results[name] = run_convergence_batch(
-            problem,
-            traces,
-            cfg,
-            num_iterations,
+    with jax.profiler.TraceAnnotation("repro.sweep", seed=seed):
+        with jax.profiler.TraceAnnotation("repro.sample_fleet"):
+            traces = sample_fleet(
+                cluster,
+                n_scenarios,
+                num_iterations,
+                burst_rate=burst_rate,
+                burst_factor_mean=burst_factor_mean,
+                burst_duration_mean=burst_duration_mean,
+                seed=seed + 1,
+            )
+        eng = as_engine_config(engine, _stacklevel=3)
+        results: dict[str, ConvergenceBatchResult] = {}
+        t0 = time.perf_counter()
+        for name, cfg in methods.items():
+            with jax.profiler.TraceAnnotation("repro.method", method=name):
+                results[name] = run_convergence_batch(
+                    problem,
+                    traces,
+                    cfg,
+                    num_iterations,
+                    cost_scale=cost_scale,
+                    eval_every=eval_every,
+                    seed=seed,
+                    engine=eng,
+                )
+        engine_seconds = time.perf_counter() - t0
+        return ConvergenceSweepOutcome(
+            results=results,
+            methods=dict(methods),
+            traces=traces,
+            problem=problem,
+            cluster=cluster,
+            num_iterations=num_iterations,
             cost_scale=cost_scale,
             eval_every=eval_every,
             seed=seed,
-            engine=eng,
+            engine_seconds=engine_seconds,
         )
-    engine_seconds = time.perf_counter() - t0
-    return ConvergenceSweepOutcome(
-        results=results,
-        methods=dict(methods),
-        traces=traces,
-        problem=problem,
-        cluster=cluster,
-        num_iterations=num_iterations,
-        cost_scale=cost_scale,
-        eval_every=eval_every,
-        seed=seed,
-        engine_seconds=engine_seconds,
-    )
 
 
 #: Calibrated parameters of the paper-scale PCA convergence sweep (Figs.
