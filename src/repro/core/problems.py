@@ -189,13 +189,17 @@ class FusedKernels:
     ``sub_blocks(V_stack, starts, widths, pad_width)`` evaluates G block
     subgradients at a static gather width (rows past each width masked to
     zero); ``suboptimality`` / ``project`` / ``regularizer_grad`` operate on
-    ``[S, ...]`` iterate stacks.  ``value_dtype`` is the dtype
-    ``sub_blocks`` returns (the fused engine sizes its in-flight value
-    buffers with it).  The raw callables are traceable from inside an outer
-    ``jax.jit`` / ``lax.scan`` (the fused engine); the ``*_jit`` fields are
-    the standalone jitted versions the numpy wrappers use.  Instances hash
-    by identity, so they can be passed as static arguments to jitted
-    drivers.
+    ``[S, ...]`` iterate stacks.  ``suboptimality_stacked`` is the same
+    gap as one float64 contraction of the data with every iterate at once:
+    it reduces per iterate as ``suboptimality`` does, but its batched dot
+    does not round like the per-iterate one, so only ``suboptimality`` is
+    batch invariant (the fused scan takes the stacked form off the CPU).
+    ``value_dtype`` is the dtype ``sub_blocks`` returns (the fused engine
+    sizes its in-flight value buffers with it).  The raw callables are
+    traceable from inside an outer ``jax.jit`` / ``lax.scan`` (the fused
+    engine); the ``*_jit`` fields are the standalone jitted versions the
+    numpy wrappers use.  Instances hash by identity, so they can be passed
+    as static arguments to jitted drivers.
     """
 
     num_samples: int
@@ -204,6 +208,7 @@ class FusedKernels:
     cost_per_row: float
     sub_blocks: Callable  # (Vb, starts, widths, pad_width) -> [G, ...]
     suboptimality: Callable  # [S, ...] -> [S]
+    suboptimality_stacked: Callable  # [B, ...] -> [B], one contraction
     project: Callable  # [S, ...] -> [S, ...]
     regularizer_grad: Callable  # [S, ...] -> [S, ...]
     # Pallas twin of sub_blocks — (Vb, starts, widths, pad_width, interpret)
@@ -384,6 +389,14 @@ class PCAProblem(FiniteSumProblem):
 
             return jax.lax.map(one, V_stack)
 
+        def suboptimality_stacked(V_stack):
+            # every iterate's k columns side by side: X64 @ [d, B k]
+            B = V_stack.shape[0]
+            V_cat = jnp.moveaxis(V_stack, 0, 1).reshape(self.dim, B * self.k)
+            xv = X64 @ V_cat.astype(jnp.float64)
+            explained = jnp.sum((xv * xv).reshape(n, B, self.k), axis=(0, 2))
+            return jnp.maximum((opt - explained) / total, 1e-16)
+
         def project(V_stack):
             # Gram-Schmidt == thin-QR orthonormalization (sign-fixed); on CPU
             # jnp.linalg.qr loops LAPACK per matrix, so rows are
@@ -399,6 +412,7 @@ class PCAProblem(FiniteSumProblem):
             cost_per_row=self.cost_per_row,
             sub_blocks=sub_blocks,
             suboptimality=suboptimality,
+            suboptimality_stacked=suboptimality_stacked,
             project=project,
             regularizer_grad=lambda V_stack: V_stack,  # ∇ 1/2||V||_F^2
             sub_blocks_pallas=sub_blocks_pallas,
@@ -525,6 +539,15 @@ class LogisticRegressionProblem(FiniteSumProblem):
         def suboptimality(V_stack):
             return jnp.maximum(objective(V_stack) - opt_obj, 1e-16)
 
+        def suboptimality_stacked(V_stack):
+            # objective_one for every iterate from one X64 @ [d, B]
+            V64 = V_stack.astype(jnp.float64)
+            z = y64[:, None] * (X64 @ V64.T)
+            obj = jnp.mean(jnp.logaddexp(0.0, -z), axis=0) + 0.5 * lam * jnp.sum(
+                V64 * V64, axis=1
+            )
+            return jnp.maximum(obj - opt_obj, 1e-16)
+
         self._kernels = FusedKernels(
             num_samples=n,
             value_shape=(self.dim,),
@@ -532,6 +555,7 @@ class LogisticRegressionProblem(FiniteSumProblem):
             cost_per_row=self.cost_per_row,
             sub_blocks=sub_blocks,
             suboptimality=suboptimality,
+            suboptimality_stacked=suboptimality_stacked,
             project=lambda V_stack: V_stack,  # G = identity
             regularizer_grad=lambda V_stack: lam * V_stack,
             sub_blocks_pallas=sub_blocks_pallas,

@@ -4,10 +4,11 @@ The host engine (:func:`repro.experiments.convergence.run_convergence_batch`
 with ``EngineConfig(kind="host")``) runs one Python iteration per training
 iteration and dispatches batched kernels from it.  This module compiles the
 *entire* iteration body — §4.2 event algebra, §3 trace replay, block
-subgradients, the §5 cache update as masked scatters, the iterate update,
-and the suboptimality evaluation — into one jittable function and scans it
-over the whole run: a single XLA dispatch for a complete ``[S]``-scenario
-training sweep, ready for accelerators.
+subgradients, the §5 cache update as masked scatters, and the iterate
+update — into one jittable function and scans it over the whole run; the
+suboptimality of the iterates at the eval steps is evaluated after the
+scan, in the same executable: a single XLA dispatch for a complete
+``[S]``-scenario training sweep, ready for accelerators.
 
 Bit-exactness contract (pinned by ``tests/test_fused.py``): for every
 scenario, the scan produces the same bits as the host engine and the scalar
@@ -123,7 +124,8 @@ from repro.precision import x64
 LB_MAX_SLOTS = 250_000
 
 #: named scopes of the scan body's phases, so device ops read
-#: ``.../<method>/while/body/<phase>/...`` in a profiler trace.  The fixed
+#: ``.../<method>/while/body/<phase>/...`` in a profiler trace; the eval
+#: runs after the scan, as ``.../<method>/phase_eval/...``.  The fixed
 #: prefix keeps every name apart from JAX's primitive and function names.
 SCAN_PHASES = tuple(
     f"phase_{p}" for p in ("events", "subgrad", "cache", "update", "eval", "lb")
@@ -198,6 +200,13 @@ class _StaticSpec:
     # this hashable spec, hence of every jit key.
     kernel_backend: str = "xla"
     kernel_interpret: bool = True
+    # the iterations whose iterates get a suboptimality, evaluated after
+    # the scan; eval_stacked (resolved eagerly, like kernel_interpret)
+    # takes FusedKernels.suboptimality_stacked, one float64 contraction of
+    # the data with all of them, where the CPU keeps the batch-invariant
+    # per-iterate lax.map that the bit-exact pins rest on
+    eval_steps: tuple[int, ...] = ()
+    eval_stacked: bool = False
 
 
 def _possible_widths(n_local: int, p: int, full: bool) -> set:
@@ -218,6 +227,8 @@ def _static_spec(
     has_churn: bool = False,
     kernel_backend: str = "xla",
     kernel_interpret: bool = True,
+    eval_steps: tuple[int, ...] = (),
+    eval_stacked: bool = False,
 ) -> _StaticSpec:
     n = problem.num_samples
     N = num_workers
@@ -285,6 +296,8 @@ def _static_spec(
         has_churn=bool(has_churn),
         kernel_backend=kernel_backend,
         kernel_interpret=bool(kernel_interpret),
+        eval_steps=tuple(int(t) for t in eval_steps),
+        eval_stacked=bool(eval_stacked),
     )
 
 
@@ -878,7 +891,6 @@ def _run_scan(
     burst_end,
     burst_factor,
     V0,
-    eval_mask,
     churn_times,
     churn_slowdown,
     churn_alive,
@@ -891,8 +903,9 @@ def _run_scan(
     subpartition grid, or the §6 candidate after Algorithm-2 alignment —
     and the cache layout (``spec.cache_mode``); everything else (trace
     replay, event algebra, subgradients, iterate update, telemetry) is
-    written once.  Under ``shard_map`` this function sees the local
-    scenario shard: every per-scenario value is row-independent, and the
+    written once; the suboptimality of ``spec.eval_steps``' iterates is
+    evaluated after the scan.  Under ``shard_map`` this function sees the
+    local scenario shard: every per-scenario value is row-independent, and the
     cross-shard-varying dynamic trip counts / ``lax.cond`` decisions only
     skip work that is an exact no-op, so shards reproduce the
     single-device bits.
@@ -969,8 +982,7 @@ def _run_scan(
         active = (burst_start <= tt) & (tt < burst_end)
         return jnp.where(active, burst_factor, 1.0).max(axis=2)
 
-    def body(carry, xs):
-        t, do_eval = xs
+    def body(carry, t):
         V = carry["V"]
         free_at = carry["free_at"]
         sub_k = carry["sub_k"]
@@ -1212,16 +1224,9 @@ def _run_scan(
                 xi = jnp.maximum(covered_f / n, 1e-12)
                 grad = grad_acc / _bcast(xi, vdim) + kernels.regularizer_grad(V)
 
-        # -- iterate update + suboptimality ---------------------------------
+        # -- iterate update (evaluated after the scan) ----------------------
         with _phase("update"):
             V_new = kernels.project((V - spec.eta * grad).astype(V.dtype))
-        with _phase("eval"):
-            subopt_t = jax.lax.cond(
-                do_eval,
-                lambda v: kernels.suboptimality(v),
-                lambda v: jnp.full((S,), jnp.nan, dtype=jnp.float64),
-                V_new,
-            )
 
         # -- commit worker state for started tasks --------------------------
         out = dict(carry)
@@ -1331,7 +1336,7 @@ def _run_scan(
         else:
             published = jnp.zeros((S,), bool)
 
-        return out, (iter_end_new, subopt_t, fresh_cnt, published)
+        return out, (iter_end_new, V_new, fresh_cnt, published)
 
     val_dtype = jnp.dtype(kernels.value_dtype)
     if spec.cache_mode == "grid":
@@ -1403,22 +1408,43 @@ def _run_scan(
             jnp.zeros((S, N, T)),
             jnp.zeros((S, N, T), dtype=bool),
         )
-    xs = (jnp.arange(T, dtype=jnp.int64), eval_mask)
     with jax.named_scope(spec.name):
-        carry, ys = jax.lax.scan(body, carry0, xs)
-    times, subopt, fresh_counts, published = ys
+        carry, ys = jax.lax.scan(body, carry0, jnp.arange(T, dtype=jnp.int64))
+        times, V_hist, fresh_counts, published = ys
+        with _phase("eval"):
+            subopt = _eval_iterates(kernels, spec, V_hist)
     evictions = carry["cache"].get(
         "evictions", jnp.zeros((S,), dtype=jnp.int64)
     )
     return (
         times.T,
-        subopt.T,
+        subopt,
         fresh_counts.T,
         carry["lat"],
         carry["cache"]["rejected"],
         evictions,
         published.T,  # [S, T] publication schedule (all-False without §6)
     )
+
+
+def _eval_iterates(kernels: FusedKernels, spec: _StaticSpec, V_hist):
+    """``[S, T]`` suboptimality of the iterates ``V_hist[t]`` (``[T, S,
+    ...]``) at ``spec.eval_steps``, NaN at every other iteration.
+
+    All E * S iterates go to the kernel in one stack: the stacked form
+    makes one float64 pass over the data for all of them (an emulated
+    float64 dot splits its data operand on every call); the per-iterate
+    map keeps each row's bits batch invariant.
+    """
+    T, S = V_hist.shape[:2]
+    steps = np.asarray(spec.eval_steps, dtype=np.int64)
+    V_ev = V_hist[steps].reshape((steps.size * S,) + kernels.value_shape)
+    if spec.eval_stacked:
+        gaps = kernels.suboptimality_stacked(V_ev)
+    else:
+        gaps = kernels.suboptimality(V_ev)
+    subopt = jnp.full((T, S), jnp.nan, dtype=jnp.float64)
+    return subopt.at[steps].set(gaps.reshape(steps.size, S)).T
 
 
 def _scan_jit_for(kernels: FusedKernels, mesh=None):
@@ -1430,9 +1456,9 @@ def _scan_jit_for(kernels: FusedKernels, mesh=None):
     process lifetime; this way the compiled executables are garbage
     collected with the problem.  With a mesh, the driver is wrapped in
     ``shard_map`` over the ``"data"`` (scenario) axis: the five slot
-    tables, ``slowdown``, ``eval_mask`` and the PRNG key are replicated,
-    every ``[S, ...]`` array is sharded on its leading axis, and so is
-    every output.
+    tables, ``slowdown``, the churn tables, the slot owners and the PRNG
+    key are replicated, every ``[S, ...]`` array is sharded on its leading
+    axis, and so is every output.
     """
     cache = getattr(kernels, "_scan_driver_jits", None)
     if cache is None:
@@ -1450,7 +1476,7 @@ def _scan_jit_for(kernels: FusedKernels, mesh=None):
         else:
             repl, data = P(), P("data")
             in_specs = (repl,) * 5 + (
-                data, data, repl, data, data, data, data, repl,
+                data, data, repl, data, data, data, data,
             ) + (repl,) * 5  # churn tables, slot owners, PRNG key
             out_specs = (data,) * 7
 
@@ -1696,6 +1722,9 @@ def prepare_scan_inputs(
     # jax.default_backend() inside a jitted wrapper bakes a stale value
     # into the cached executable (the kernels/ops.py bug class)
     kernel_interpret = jax.default_backend() == "cpu"
+    # and the eval's form: one stacked contraction off the CPU, the
+    # batch-invariant per-iterate map on it
+    eval_stacked = jax.default_backend() != "cpu"
     tiled = cap.code == CAP_TILED
     S = traces.num_scenarios
     T = num_iterations
@@ -1703,6 +1732,7 @@ def prepare_scan_inputs(
         raise ValueError(
             f"traces hold {traces.horizon} draws/worker but {T} iterations requested"
         )
+    eval_steps = sorted({*range(0, T, eval_every), T - 1})
     universe = None
     active_cap = 0
     if config.load_balance and config.uses_cache:
@@ -1731,12 +1761,11 @@ def prepare_scan_inputs(
         has_churn=traces.churn is not None,
         kernel_backend=kernel_backend,
         kernel_interpret=kernel_interpret,
+        eval_steps=eval_steps,
+        eval_stacked=eval_stacked,
     )
     kernels = problem.fused_kernels()
     V0 = np.repeat(problem.init(seed)[None], S, axis=0)
-    eval_mask = np.zeros(T, dtype=bool)
-    eval_mask[::eval_every] = True
-    eval_mask[T - 1] = True
 
     def padded(a):
         if pad == 0:
@@ -1754,7 +1783,6 @@ def prepare_scan_inputs(
             jnp.asarray(padded(traces.burst_end)) if has_b else empty,
             jnp.asarray(padded(traces.burst_factor)) if has_b else empty,
             jnp.asarray(padded(V0)),
-            jnp.asarray(eval_mask),
         )
         if universe is not None:
             slot_table = jnp.asarray(universe.slot_table)
@@ -1891,7 +1919,11 @@ def scan_counts(spec: _StaticSpec, result) -> dict[str, int]:
       finite latencies beyond the fresh ones);
     * ``rejected``: stale results the cache refused;
     * ``walk_ranks``: ranks the §5 walk visited, S*T times the event table's
-      width (2N with stale arrivals, N without; 0 without a cache).
+      width (2N with stale arrivals, N without; 0 without a cache);
+    * ``eval_iterates``: iterates whose suboptimality was evaluated, S
+      times the E eval steps;
+    * ``eval_passes``: float64 contractions over the data made for them
+      (per device), 1 in the stacked form and E*S in the per-iterate map.
     """
     S, T, N = result.per_worker_latency.shape
     fresh = int(result.fresh_counts.sum())
@@ -1901,9 +1933,12 @@ def scan_counts(spec: _StaticSpec, result) -> dict[str, int]:
         else fresh
     )
     walk = (2 * N if spec.accepts_stale else N) if spec.uses_cache else 0
+    evals = S * len(spec.eval_steps)
     return dict(
         subgrad_rows=S * T * N * len(spec.buckets),
         events=events,
         rejected=int(result.rejected_stale.sum()),
         walk_ranks=S * T * walk,
+        eval_iterates=evals,
+        eval_passes=1 if spec.eval_stacked else evals,
     )

@@ -13,6 +13,10 @@ scan == host == scalar, including the §5.1 margin and the §6
 load-balancing routing.
 """
 
+import dataclasses
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -29,9 +33,11 @@ from repro.experiments.convergence import (
     paper_scale_pca_sweep,
     run_convergence_batch,
 )
+from repro.experiments import fused
 from repro.experiments.fused import run_convergence_scan
 from repro.experiments.results import convergence_ordering
 from repro.latency.model import make_heterogeneous_cluster, sample_fleet
+from repro.precision import x64
 
 
 @pytest.fixture(scope="module")
@@ -125,6 +131,25 @@ class TestKernelProperties:
         batch = prob.suboptimality_batch(Vs)
         for s in range(4):
             assert batch[s] == prob.suboptimality(Vs[s])
+
+    @pytest.mark.parametrize("B", [1, 4, 13])
+    @pytest.mark.parametrize("which", ["logreg", "pca"])
+    def test_suboptimality_stacked_matches_rows(self, which, B, logreg_small, pca_small):
+        """The stacked form (one float64 contraction with every iterate,
+        the fused scan's eval off the CPU) gives each row's gap to a
+        relative 1e-12 of the per-iterate kernel."""
+        prob = logreg_small if which == "logreg" else pca_small
+        V0 = prob.init(0)
+        rng = np.random.default_rng(B)
+        Vs = np.stack(
+            [V0 + rng.normal(scale=0.05, size=V0.shape).astype(np.float32)
+             for _ in range(B)]
+        )
+        kernels = prob.fused_kernels()
+        with x64():
+            stacked = np.asarray(jax.jit(kernels.suboptimality_stacked)(jnp.asarray(Vs)))
+        assert stacked.shape == (B,) and stacked.dtype == np.float64
+        np.testing.assert_allclose(stacked, prob.suboptimality_batch(Vs), rtol=1e-12, atol=0)
 
     def test_pca_projection_batch_invariant(self, pca_small):
         rng = np.random.default_rng(1)
@@ -221,6 +246,33 @@ class TestScanVsHost:
         np.testing.assert_array_equal(h.times, auto.times[0])
         np.testing.assert_array_equal(h.suboptimality, auto.suboptimality[0])
         assert list(h.repartition_events) == list(auto.repartition_events[0])
+
+    @pytest.mark.parametrize("which", ["logreg", "pca"])
+    def test_stacked_eval_after_the_scan(self, which, logreg_small, pca_small):
+        """The scan with the stacked eval (the form it takes off the CPU)
+        keeps every other output bit for bit, NaN where no eval step is,
+        and each gap to a relative 1e-12 of the per-iterate map."""
+        prob = logreg_small if which == "logreg" else pca_small
+        _, traces = small_fleet()
+        cfg = MethodConfig(name="dsag", w=2, eta=0.25, subpartitions=3)
+        spec, kernels, args = fused.prepare_scan_inputs(prob, traces, cfg, 25, eval_every=4)
+        assert not spec.eval_stacked  # the CPU keeps the bit-exact map
+        assert spec.eval_steps == (0, 4, 8, 12, 16, 20, 24)
+        run = fused._scan_jit_for(kernels)
+        with x64():
+            mapped = [np.asarray(o) for o in run(kernels, spec, *args)]
+            stacked_spec = dataclasses.replace(spec, eval_stacked=True)
+            stacked = [np.asarray(o) for o in run(kernels, stacked_spec, *args)]
+        for i, (a, b) in enumerate(zip(mapped, stacked)):
+            if i != 1:
+                np.testing.assert_array_equal(a, b)
+        sub_m, sub_s = mapped[1], stacked[1]
+        assert sub_m.shape == (traces.num_scenarios, 25)
+        evaluated = np.zeros(25, bool)
+        evaluated[list(spec.eval_steps)] = True
+        np.testing.assert_array_equal(np.isfinite(sub_s), np.broadcast_to(evaluated, sub_s.shape))
+        np.testing.assert_array_equal(np.isnan(sub_m), np.isnan(sub_s))
+        np.testing.assert_allclose(sub_s, sub_m, rtol=1e-12, atol=0)
 
     def test_unknown_engine_rejected(self, logreg_small):
         cluster, traces = small_fleet()

@@ -4,10 +4,11 @@ The TPU compiler is installed with jaxlib, and it compiles for a
 topology that is described rather than attached.  These tests hand it the
 Pallas block-subgradient kernels at the paper-scale shapes with their real
 ``width_bucket`` pads, the grid-cache kernel at the 100-worker logreg grid
-shapes (which XLA:TPU refuses, so the engine refuses it first), and one
-whole fused scan body, plus the §6 optimizer round (Algorithm 1) at the
-100-worker grid's shapes.  What Mosaic or XLA:TPU would refuse on the chip
-fails here.  Nothing runs: a compile says nothing about results or times.
+shapes (which XLA:TPU refuses, so the engine refuses it first), the
+whole fused scan of each problem with the eval it takes on the chip, and
+the §6 optimizer round (Algorithm 1) at the 100-worker grid's shapes.
+What Mosaic or XLA:TPU would refuse on the chip fails here.  Nothing
+runs: a compile says nothing about results or times.
 
 The topology is described inside a module fixture, never at import time:
 only one process at a time may load the TPU library, and the test workers
@@ -17,6 +18,7 @@ all import this file.
 import dataclasses
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -139,12 +141,11 @@ def test_grid_cache_update_is_refused(one_chip, monkeypatch):
     assert fused.kernel_backend_capability(prob, "pallas", sgd).supported
 
 
-def test_fused_scan_body_compiles_for_logreg_grid(one_chip):
-    """The whole float64 scan body of the 100-worker logreg grid (DSAG,
-    grid cache, xla backend), at a reduced iteration count."""
-    N, S, T, sp = 100, 10, 4, 10
-    X, y = make_higgs_like(LR_ROWS, seed=0)
-    prob = LogisticRegressionProblem(X=X, y=y)
+def _compile_scan_for_chip(one_chip, prob, N, S, T, sp, eta, eval_every):
+    """The DSAG scan (grid cache, xla backend) as the chip would run it:
+    the spec made here takes the CPU's forms, so they are set to the
+    chip's (compiled kernels, the stacked eval).  Returns the compiled
+    scan and the ``op_name`` of each of its ops."""
     c_task = prob.compute_cost(1, max(prob.num_samples // (N * sp), 1))
     cluster = make_heterogeneous_cluster(N, seed=0, burst_rate=0.0, load_unit=c_task)
     traces = sample_fleet(
@@ -152,9 +153,11 @@ def test_fused_scan_body_compiles_for_logreg_grid(one_chip):
         burst_factor_mean=HEAVY_BURSTS.factor_mean,
         burst_duration_mean=HEAVY_BURSTS.duration_mean, seed=1,
     )
-    cfg = default_convergence_methods(N, w=80, eta=0.25, subpartitions=sp)["dsag"]
-    spec, kernels, scan_args = fused.prepare_scan_inputs(prob, traces, cfg, T)
-    spec = dataclasses.replace(spec, kernel_interpret=False)
+    cfg = default_convergence_methods(N, w=int(0.8 * N), eta=eta, subpartitions=sp)["dsag"]
+    spec, kernels, scan_args = fused.prepare_scan_inputs(
+        prob, traces, cfg, T, eval_every=eval_every
+    )
+    spec = dataclasses.replace(spec, kernel_interpret=False, eval_stacked=True)
     with x64():
         shapes = [_sds(np.shape(a), a.dtype, one_chip) for a in scan_args]
         compiled = (
@@ -163,6 +166,38 @@ def test_fused_scan_body_compiles_for_logreg_grid(one_chip):
             .compile()
         )
     assert compiled.memory_analysis() is not None
+    return compiled, re.findall(r'op_name="([^"]*)"', compiled.as_text())
+
+
+def _assert_eval_after_the_scan(op_names):
+    """The stacked eval is one pass after the scan: its ops read
+    ``jit(_run_scan)/dsag/phase_eval/...`` and none lies in a loop."""
+    evals = [p for p in op_names if "phase_eval" in p.split("/")]
+    assert any(p.startswith("jit(_run_scan)/dsag/phase_eval/dot_general") for p in evals)
+    assert not any("while" in p.split("/") for p in evals)
+    assert any("/while/body/" in p and "phase_subgrad" in p.split("/") for p in op_names)
+
+
+def test_fused_scan_body_compiles_for_logreg_grid(one_chip):
+    """The whole float64 scan of the 100-worker logreg grid (DSAG, grid
+    cache, xla backend, stacked eval), at a reduced iteration count."""
+    X, y = make_higgs_like(LR_ROWS, seed=0)
+    prob = LogisticRegressionProblem(X=X, y=y)
+    _, op_names = _compile_scan_for_chip(
+        one_chip, prob, N=100, S=10, T=10, sp=10, eta=0.25, eval_every=5
+    )
+    _assert_eval_after_the_scan(op_names)
+
+
+def test_fused_scan_compiles_for_pca(one_chip):
+    """The same for the 50-worker PCA job (5 subpartitions, k = 3), at a
+    reduced size: the stacked eval's one ``[n, d] @ [d, B k]`` float64
+    contraction sits after the scan."""
+    prob = PCAProblem(X=make_genomics_like_matrix(2_000, 256, seed=0), k=PCA_K)
+    _, op_names = _compile_scan_for_chip(
+        one_chip, prob, N=50, S=4, T=8, sp=5, eta=0.9, eval_every=4
+    )
+    _assert_eval_after_the_scan(op_names)
 
 
 def test_lb_update_compiles_for_logreg_grid(one_chip):

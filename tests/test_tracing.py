@@ -13,6 +13,7 @@ import glob
 import re
 
 import jax
+import numpy as np
 import pytest
 
 from repro.cluster.simulator import TraceLatencySource, TrainingSimulator
@@ -24,8 +25,10 @@ from repro.precision import x64
 
 N, S, T, EVAL_EVERY = 6, 2, 12, 3
 METHODS = ("dsag", "sag", "sgd", "coded")
-#: the phases every default method's body has (``lb`` is §6 only)
-BODY_PHASES = ("phase_events", "phase_subgrad", "phase_cache", "phase_update", "phase_eval")
+#: the phases every default method's scan body has (``lb`` is §6 only);
+#: ``phase_eval`` follows the scan
+BODY_PHASES = ("phase_events", "phase_subgrad", "phase_cache", "phase_update")
+EVAL_PHASE = "phase_eval"
 _META = re.compile(r", metadata=\{[^}]*\}")
 _TABLES = re.compile(r"\n(FileNames|FunctionNames|FileLocations|StackFrames)\n(?:[^\n]+\n)*")
 
@@ -71,6 +74,15 @@ def hlo(setup):
     return out
 
 
+def _assert_eval_after_scan(op_names, method_path: str):
+    """The eval runs once, after the scan, inside the method's scope: its
+    ops read ``<method_path>/phase_eval/...``, and none lies in a loop
+    outside the scope (the scan's ``while/body``)."""
+    evals = [p for p in op_names if EVAL_PHASE in p.split("/")]
+    assert any(p.startswith(f"{method_path}/{EVAL_PHASE}/") for p in evals)
+    assert not any("while" in p.split(EVAL_PHASE)[0] for p in evals)
+
+
 @pytest.mark.parametrize("name", METHODS)
 def test_every_phase_scope_is_in_the_hlo(hlo, name):
     scoped, plain = hlo[name]
@@ -81,6 +93,7 @@ def test_every_phase_scope_is_in_the_hlo(hlo, name):
         assert paths, phase
         # the method's scope sits around the scan, the phase inside its body
         assert any(p.startswith(f"jit(_run_scan)/{name}/while/body/") for p in paths), phase
+    _assert_eval_after_scan(op_names, f"jit(_run_scan)/{name}")
     assert not any(phase in plain for phase in fused.SCAN_PHASES)
 
 
@@ -115,6 +128,7 @@ def test_scopes_under_shard_map(setup):
     for phase in BODY_PHASES:
         assert any(p.startswith("jit(_run_scan_sharded)/shard_map/dsag/while/body/")
                    and phase in p.split("/") for p in op_names), phase
+    _assert_eval_after_scan(op_names, "jit(_run_scan_sharded)/shard_map/dsag")
 
 
 def test_scope_names_are_apart_from_jax_names():
@@ -184,9 +198,14 @@ def test_sweep_spans_nest_with_their_arguments(setup, tmp_path):
         if name in counts:
             (c,) = [c for c in by_name["repro.counts"] if c[3]["method"] == name]
             assert _inside(c, m) and c[1] >= fetch[2]
-            spec, _, _ = fused.prepare_scan_inputs(problem, out.traces, methods[name], T)
+            spec, _, _ = fused.prepare_scan_inputs(
+                problem, out.traces, methods[name], T, eval_every=EVAL_EVERY
+            )
             assert {k: v for k, v in c[3].items() if k != "method"} == fused.scan_counts(
                 spec, out.results[name])
+            # the post-scan eval's counters: on the CPU one pass per iterate
+            n_evals = len(range(0, T, EVAL_EVERY)) + ((T - 1) % EVAL_EVERY != 0)
+            assert c[3]["eval_iterates"] == c[3]["eval_passes"] == S * n_evals
 
 
 @pytest.mark.parametrize("name", ["dsag", "sag", "sgd"])
@@ -197,7 +216,7 @@ def test_counters_match_the_scalar_simulator(setup, name):
     cfg = methods[name]
     res = fused.run_convergence_scan(problem, traces, cfg, T, eval_every=EVAL_EVERY)
     spec, _, _ = fused.prepare_scan_inputs(problem, traces, cfg, T, eval_every=EVAL_EVERY)
-    events = rejected = 0
+    events = rejected = evals = 0
     for s in range(S):
         h = TrainingSimulator(
             problem, cluster, cfg, eval_every=EVAL_EVERY,
@@ -209,6 +228,7 @@ def test_counters_match_the_scalar_simulator(setup, name):
         if cfg.accepts_stale:
             events += int(h.flush_stream.sum()) + h.rejected_stale
         rejected += h.rejected_stale
+        evals += int(np.isfinite(h.suboptimality).sum())
     counts = fused.scan_counts(spec, res)
     n_buckets = len(spec.buckets)
     assert n_buckets >= 1
@@ -220,3 +240,6 @@ def test_counters_match_the_scalar_simulator(setup, name):
     assert 0 < counts["events"] <= counts["subgrad_rows"]
     if walk:
         assert counts["events"] <= counts["walk_ranks"]
+    # the scalar simulator evaluates the same iterations
+    assert counts["eval_iterates"] == evals == int(np.isfinite(res.suboptimality).sum())
+    assert counts["eval_passes"] == evals  # the per-iterate map on the CPU
