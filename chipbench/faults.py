@@ -1,6 +1,7 @@
 """Faults planted in the timed path, to show that ``correct`` catches them:
 by the tests on the CPU, and by ``calibrate.py`` at a cell's own size on
-the chip.
+the chip.  ``FAULTS`` break the sweep form's path, ``LIVE_FAULTS`` the
+live form's; both name the same three faults.
 
 Each fault takes a ``setattr(obj, name, value)`` (pytest's
 ``monkeypatch.setattr``, or ``Patches.setattr``) and is planted before the
@@ -66,6 +67,72 @@ def answer_altered(setattr):
 
 
 FAULTS = {f.__name__: f for f in (state_unchanged, half_batch, answer_altered)}
+
+
+# -- the live form: the program's Trainer, Tier-1 step and Tier-2 controller --
+
+
+def live_state_unchanged(setattr):
+    """A step that returns its state unchanged: the compiled step hands
+    back the parameters it was given."""
+    from repro.launch import train
+
+    real = train.make_train_step
+
+    def make_train_step(*a, **kw):
+        step = real(*a, **kw)
+
+        def frozen(state, *args):
+            new, metrics = step(state, *args)
+            return {**new, "params": state["params"]}, metrics
+
+        return frozen
+
+    setattr(train, "make_train_step", make_train_step)
+
+
+def live_half_batch(setattr):
+    """Half of the batch left out, the mean taken over the rest: every
+    second group's gradient is dropped before the cache update, the others
+    doubled."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import dsag_pjit
+
+    real = dsag_pjit.dsag_update
+
+    def dsag_update(dsag, group_grads, mask, flush, evict=None):
+        def half(g):
+            keep = (jnp.arange(g.shape[0]) % 2 == 0).astype(g.dtype) * 2
+            return g * keep.reshape((-1,) + (1,) * (g.ndim - 1))
+
+        return real(dsag, jax.tree.map(half, group_grads), mask, flush, evict)
+
+    setattr(dsag_pjit, "dsag_update", dsag_update)
+
+
+def live_answer_altered(setattr):
+    """An answer altered where it is produced: the Tier-2 controller flips
+    group 0's mask bit on each job's second step."""
+    from repro.ft.runtime import DeadlineController
+
+    real = DeadlineController.step_inputs
+
+    def step_inputs(self, *a, **kw):
+        out = real(self, *a, **kw)
+        self.fault_calls = getattr(self, "fault_calls", 0) + 1
+        if self.fault_calls == 2:
+            out.mask = out.mask.copy()
+            out.mask[0] = not out.mask[0]
+        return out
+
+    setattr(DeadlineController, "step_inputs", step_inputs)
+
+
+LIVE_FAULTS = {f.__name__.removeprefix("live_"): f
+               for f in (live_state_unchanged, live_half_batch, live_answer_altered)}
+FAULTS_OF_FORM = {"sweep": FAULTS, "live": LIVE_FAULTS}
 
 
 class Patches:

@@ -257,7 +257,8 @@ def run(ctx) -> dict:
             "trace": red,
             "requests": len(kept),
             "useful_flops": sum(useful_flops(cfg, kind, r) for _, r in kept),
-            "peak_flops": peaks_for(device["kind"]).flops if device["platform"] == "tpu" else None,
+            "peak_flops": (peaks_for(device["kind"]).flops * device["count"]
+                           if device["platform"] == "tpu" else None),
         }
         metrics = ctx.read_per_layer(reading)
     return {

@@ -58,7 +58,8 @@ def device_block(devs) -> dict:
 
 
 class CompileCounter:
-    """Counts XLA backend compilations (a persistent-cache hit is not one)."""
+    """Counts XLA backend compilations; the event also wraps a program loaded
+    from the persistent cache, so a cache hit counts as one too."""
 
     def __init__(self):
         import jax

@@ -85,6 +85,11 @@ def main(argv=None, *, allow_cpu: bool = False) -> int:
     from repro.compile_cache import enable_compile_cache
 
     print(f"compile cache: {enable_compile_cache()}", file=sys.stderr)
+    import jax
+
+    # every program goes to the persistent cache, however short its
+    # compile, so that only a checkout's first run compiles
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     compiles = harness.CompileCounter()
     import importlib
 

@@ -46,6 +46,37 @@ def test_control_fails(tiny):
     assert not compare.judge(numbers, limits)[0]
 
 
+def test_reference_pool_matches_the_serial_replay(tiny):
+    """``calibrate.py``'s pool of workers gives the serial replay's runs,
+    in the configuration's precision and one step lower: the event streams
+    to the bit, the suboptimality to round-off in the precision it is
+    computed in (a worker's BLAS runs one thread, so its sums take another
+    order)."""
+    import numpy as np
+
+    from chipbench.calibrate import ReferencePool
+
+    cfg, _ = tiny
+    kind, data, fleet = _parts(cfg)
+    seed = form.sweep_seed(2**31 + 29, 1)
+    pool = ReferencePool(workers=2)
+    try:
+        for hi, precision, rtol in (("float64", {}, 1e-12),
+                                    ("float32", {"hi": "float32", "lo": BF16}, 1e-5)):
+            serial = as_program(form.reference_sweep(
+                cfg, form_traffic(), kind.reference(data, cfg, **precision), fleet, seed, hi=hi))
+            pooled = as_program(pool.sweep(cfg, form_traffic(), fleet, seed, precision, hi=hi))
+            for name, runs in serial.items():
+                for key, value in runs.items():
+                    got, want = np.asarray(pooled[name][key]), np.asarray(value)
+                    if key == "suboptimality":
+                        np.testing.assert_allclose(got, want, rtol=rtol, err_msg=name)
+                    else:
+                        np.testing.assert_array_equal(got, want, err_msg=f"{name} {key}")
+    finally:
+        pool.close()
+
+
 def form_traffic():
     from conftest import TRAFFIC
 
